@@ -53,6 +53,7 @@ import numpy as np
 from repro.core.omp import (OMPAnytimeState, OMPIncState, _block_cap,
                             _empty_inc_state, _grow_prefix, _nnls_active_cached,
                             _pad_slots, _run_session_block, omp_session_extend)
+from repro.kernels.ref import PRECISION
 
 __all__ = [
     "DowndateInfo",
@@ -87,7 +88,7 @@ def _truncate_buffers(st: OMPIncState, target, t, lam: float,
     colcache = jnp.where(jnp.arange(wc)[None, :] < t, st.colcache, 0.0)
     absrow = jnp.where(keep, jnp.sum(jnp.abs(gram), axis=1), 0.0)
     w = _nnls_active_cached(gram, absrow, rows, tcorr, mask, lam, nnls_iters)
-    resid = target - w @ rows
+    resid = target - jnp.dot(w, rows, precision=PRECISION)
     err = jnp.sum(resid**2) + lam * jnp.sum(w**2)
     return OMPIncState(indices, mask, w, colcache, gram, absrow, tcorr,
                        rows, resid, err)

@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.gradmatch import SelectionResult, _normalize
 from repro.core.omp import _nnls_active_cached
+from repro.kernels.ref import PRECISION
 
 
 def sharded_omp_select(
@@ -70,7 +71,8 @@ def sharded_omp_select(
             (indices, mask, weights, rows, gram, absrow, tcorr, residual,
              err) = carry
             # 1) local scores against the shared residual.
-            scores = g_local @ residual                      # (n_local,)
+            scores = jnp.dot(g_local, residual,
+                             precision=PRECISION)             # (n_local,)
             # Slots owned by other shards (or unused) point at the
             # out-of-bounds sentinel n_local, dropped by the scatter —
             # an in-bounds sentinel would spuriously mark local candidate
@@ -102,14 +104,15 @@ def sharded_omp_select(
             # 4) grow the replicated Gram/target-correlation caches by one
             #    row/col (O(k d), vs the O(k^2 d) rebuild they replace) and
             #    re-solve the small NNLS on the cached buffers.
-            row_vals = jnp.where(mask, rows @ g_e, 0.0)
+            row_vals = jnp.where(
+                mask, jnp.dot(rows, g_e, precision=PRECISION), 0.0)
             gram = gram.at[t, :].set(row_vals).at[:, t].set(row_vals)
             absrow = jnp.where(mask, absrow + jnp.abs(row_vals), 0.0)
             absrow = absrow.at[t].set(jnp.sum(jnp.abs(row_vals)))
-            tcorr = tcorr.at[t].set(jnp.dot(g_e, tgt))
+            tcorr = tcorr.at[t].set(jnp.dot(g_e, tgt, precision=PRECISION))
             weights = _nnls_active_cached(gram, absrow, rows, tcorr, mask,
                                           lam, nnls_iters)
-            approx = weights @ rows
+            approx = jnp.dot(weights, rows, precision=PRECISION)
             residual = tgt - approx
             err = jnp.sum(residual ** 2) + lam * jnp.sum(weights ** 2)
             return (indices, mask, weights, rows, gram, absrow, tcorr,
@@ -181,9 +184,7 @@ def sharded_gradmatch_pb(
 
 @functools.lru_cache(maxsize=None)
 def _pmap_scorer(m_loc: int, absolute: bool, need_norms: bool):
-    """pmap'd per-device top-m chunk scorer (plain pmap — no shard_map, so
-    it runs on older jax without AxisType; the shim note in DESIGN.md §3
-    does not apply here)."""
+    """pmap'd per-device top-m chunk scorer."""
     from repro.core.streaming import _score_chunk_impl
 
     def local(chunk, ok, gids, offset, residual, sel_idx, sel_mask):
@@ -242,8 +243,7 @@ def pmap_chunk_topm(chunk, pool_ok, gids, offset, residual, sel_idx,
 @functools.lru_cache(maxsize=None)
 def _pmap_fl_scorer(per: int, row_block: int):
     """pmap'd per-device FL gain scorer over a candidate-column shard
-    (plain pmap — no shard_map, so it runs on older jax; same pattern as
-    ``_pmap_scorer`` above)."""
+    (same pattern as ``_pmap_scorer`` above)."""
     from repro.core import greedy as greedy_lib
 
     def local(cand, cand_sqn, avail_l, offset, grads, sqnorms, cover,
@@ -360,8 +360,7 @@ def fl_greedy_pmap(grads, k: int, valid=None, l_max=None,
 @functools.lru_cache(maxsize=None)
 def _pmap_partition_solver(k: int, lam: float, eps: float, nnls_iters: int,
                            method: str, block: int):
-    """pmap'd per-device partition OMP (plain pmap — no shard_map, so it
-    runs on older jax without AxisType; same pattern as ``_pmap_scorer``
+    """pmap'd per-device partition OMP (same pattern as ``_pmap_scorer``
     above).  One device solves one whole partition; partitions are
     independent problems, so no collective is ever needed."""
     from repro.core.omp import omp_select

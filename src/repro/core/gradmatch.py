@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.core import omp as omp_lib
 from repro.core import proxies as proxy_lib
+from repro.kernels.ref import PRECISION
 
 
 class SelectionResult(NamedTuple):
@@ -95,7 +96,7 @@ def gradmatch_per_class(
     sizes = np.bincount(labels_np[in_range], minlength=num_classes)
     quotas = omp_lib.split_budget(k, sizes)
     onehot = jax.nn.one_hot(labels, num_classes, dtype=grads.dtype)  # (n, C)
-    targets = onehot.T @ grads                                       # (C, d)
+    targets = jnp.dot(onehot.T, grads, precision=PRECISION)          # (C, d)
     idx, w, mask = omp_lib.omp_select_per_class(
         grads, labels, targets, num_classes, 0, lam=lam, eps=eps,
         method=method, quotas=quotas,

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.kernels import ops, ref
+from repro.kernels.ref import PRECISION
 
 _NEG_INF = jnp.float32(-jnp.inf)
 
@@ -71,7 +72,8 @@ def pairwise_sim(grads: jax.Array, dist_fn=None,
         d2 = dist_fn(grads, grads)
     else:
         sq = jnp.sum(grads**2, axis=-1)
-        d2 = sq[:, None] + sq[None, :] - 2.0 * (grads @ grads.T)
+        d2 = sq[:, None] + sq[None, :] - 2.0 * jnp.dot(
+            grads, grads.T, precision=PRECISION)
     dist = jnp.sqrt(jnp.maximum(d2, 0.0))
     lm = jnp.max(dist) if l_max is None else jnp.asarray(l_max, jnp.float32)
     return lm - dist
@@ -163,7 +165,7 @@ def fl_rows(grads: jax.Array, sqnorms: jax.Array, row_okf: jax.Array,
     exactly the ``cover`` update vector for candidate ids[B]."""
     cand = grads[ids]                                      # (B, d)
     d2 = (sqnorms[ids][:, None] + sqnorms[None, :]
-          - 2.0 * (cand @ grads.T))
+          - 2.0 * jnp.dot(cand, grads.T, precision=PRECISION))
     return (l_max - jnp.sqrt(jnp.maximum(d2, 0.0))) * row_okf[None, :]
 
 

@@ -61,6 +61,7 @@ import numpy as np
 from jax import lax
 
 from repro.kernels import ops
+from repro.kernels.ref import PRECISION
 
 
 class OMPState(NamedTuple):
@@ -119,7 +120,7 @@ def _nnls_active(
     step = 1.0 / lip
 
     def body(_, w):
-        grad = a @ w - c
+        grad = jnp.dot(a, w, precision=PRECISION) - c
         w = jnp.maximum(w - step * grad, 0.0)
         return w * m
 
@@ -153,9 +154,10 @@ def _nnls_active_cached(
 
     def body(_, w):
         if use_factor:
-            aw = rows @ (w @ rows) + lam * w
+            aw = jnp.dot(rows, jnp.dot(w, rows, precision=PRECISION),
+                         precision=PRECISION) + lam * w
         else:
-            aw = gram @ w + lam * w
+            aw = jnp.dot(gram, w, precision=PRECISION) + lam * w
         w = jnp.maximum(w - step * (aw - c), 0.0)
         return w * m
 
@@ -172,7 +174,7 @@ def _omp_select_dense(grads, target, k, lam, eps, nnls_iters, positive,
     def correlate(residual):
         if corr_fn is not None:
             return corr_fn(grads, residual)
-        return grads @ residual
+        return jnp.dot(grads, residual, precision=PRECISION)
 
     def body(t, state: OMPState):
         # 1) residual correlations;  already-selected / invalid candidates out.
@@ -197,12 +199,12 @@ def _omp_select_dense(grads, target, k, lam, eps, nnls_iters, positive,
         # 2) re-solve non-negative ridge LS on the active set.
         sel = jnp.where(new_mask, new_indices, 0)
         g_s = grads[sel] * new_mask[:, None].astype(grads.dtype)  # (k, d)
-        gram = g_s @ g_s.T
-        corr = g_s @ target
+        gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
+        corr = jnp.dot(g_s, target, precision=PRECISION)
         w = _nnls_active(gram, corr, new_mask, lam, nnls_iters)
 
         # 3) residual + error refresh.
-        approx = w @ g_s
+        approx = jnp.dot(w, g_s, precision=PRECISION)
         residual = target - approx
         err = jnp.sum(residual**2) + lam * jnp.sum(w**2)
         return OMPState(new_indices, new_mask, w, residual, err)
@@ -289,7 +291,8 @@ def _inc_body_factory(grads, target, c0, valid, lam, eps, nnls_iters,
                 row_vals = jnp.where(mask_p, colcache[e], 0.0) * growf
             else:
                 colcache = st.colcache
-                row_vals = jnp.where(mask_p, rows @ g_e, 0.0)
+                row_vals = jnp.where(
+                    mask_p, jnp.dot(rows, g_e, precision=PRECISION), 0.0)
             gram = st.gram.at[t, :].set(row_vals).at[:, t].set(row_vals)
             # Gershgorin row sums pick up the new row/col in O(p).
             absrow = jnp.where(mask_p, st.gram_absrow + jnp.abs(row_vals),
@@ -303,7 +306,7 @@ def _inc_body_factory(grads, target, c0, valid, lam, eps, nnls_iters,
             # ||r||^2 = ||g_tgt||^2 - 2 w^T c_S + w^T A w, evaluated in the
             # factored form over cached rows (immune to the cancellation
             # the expanded form suffers near the eps-stop).
-            resid = target - w @ rows
+            resid = target - jnp.dot(w, rows, precision=PRECISION)
             err = jnp.sum(resid**2) + lam * jnp.sum(w**2)
             return OMPIncState(indices, mask, w, colcache, gram, absrow,
                                tcorr, rows, resid, err)
@@ -751,7 +754,8 @@ def _omp_select_batched_incremental(grads, targets, k, lam, eps, nnls_iters,
             else:
                 colcache = st.colcache
                 row_vals = jnp.where(
-                    mask_p, jnp.einsum("bpd,bd->bp", rows, g_e), 0.0)
+                    mask_p, jnp.einsum("bpd,bd->bp", rows, g_e,
+                                       precision=PRECISION), 0.0)
             gram = st.gram.at[:, t, :].set(row_vals).at[:, :, t].set(row_vals)
             absrow = jnp.where(mask_p,
                                st.gram_absrow + jnp.abs(row_vals), 0.0)
@@ -759,7 +763,8 @@ def _omp_select_batched_incremental(grads, targets, k, lam, eps, nnls_iters,
             tcorr = st.tcorr.at[:, t].set(c0_t[e, bcol] * growf)
 
             w = nnls_b(gram, absrow, rows, tcorr, mask_p, lam, nnls_iters)
-            resid = targets - jnp.einsum("bp,bpd->bd", w, rows)
+            resid = targets - jnp.einsum("bp,bpd->bd", w, rows,
+                                         precision=PRECISION)
             err = jnp.sum(resid**2, axis=1) + lam * jnp.sum(w**2, axis=1)
             return OMPBatchState(indices, mask, w, colcache, gram, absrow,
                                  tcorr, rows, resid, err)
@@ -932,8 +937,8 @@ def omp_select_per_class(
         # quota-sized active set against the class target.
         sel = jnp.where(mask, idx, 0)
         g_s = grads[sel] * mask[:, None].astype(grads.dtype)
-        gram = g_s @ g_s.T
-        corr = g_s @ target.astype(grads.dtype)
+        gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
+        corr = jnp.dot(g_s, target.astype(grads.dtype), precision=PRECISION)
         w = _nnls_active(gram, corr, mask, lam, nnls_iters)
         return idx, jnp.where(mask, w, 0.0), mask
 
@@ -953,5 +958,5 @@ def matching_error(
     """
     sel = jnp.where(mask, indices, 0)
     g_s = grads[sel] * mask[:, None].astype(grads.dtype)
-    resid = target - weights @ g_s
+    resid = target - jnp.dot(weights, g_s, precision=PRECISION)
     return jnp.sum(resid**2) + lam * jnp.sum(weights**2)

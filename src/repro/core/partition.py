@@ -16,8 +16,8 @@ The three layers here:
   streams) and split the budget exactly (remainder to the largest
   partitions, quotas capped at partition size, surplus rebalanced).
 * per-partition solves — device-parallel via plain ``pmap``
-  (``distributed.pmap_partition_omp``, the ``_pmap_scorer`` pattern; no
-  shard_map on this jax) for resident pools, or chunk-wise via the PR-5/6
+  (``distributed.pmap_partition_omp``, the ``_pmap_scorer`` pattern) for
+  resident pools, or chunk-wise via the PR-5/6
   streaming engine (``subrange_chunks`` views of one shared loader) for
   out-of-core partitions.  Each partition matches its own gradient-sum
   target; the targets sum to the global eq.-2 target, so the union of
@@ -47,6 +47,7 @@ from repro.core import omp as omp_lib
 from repro.core import streaming as stream_lib
 from repro.core.gradmatch import SelectionResult, _normalize
 from repro.core.omp import split_budget
+from repro.kernels.ref import PRECISION
 
 __all__ = [
     "PartitionPlan", "PartitionStats", "make_plan", "split_budget",
@@ -235,7 +236,7 @@ def gradmatch_partitioned(
         g_j = jnp.asarray(pool_np * valid_np[:, None])
         onehot = jax.nn.one_hot(jnp.asarray(plan.assign), p_count,
                                 dtype=g_j.dtype)
-        targets_p = onehot.T @ g_j
+        targets_p = jnp.dot(onehot.T, g_j, precision=PRECISION)
         g_target = jnp.sum(targets_p, axis=0)
     else:
         targets_p = jnp.sum(jnp.asarray(parts)

@@ -80,6 +80,7 @@ from repro.core.omp import _nnls_active_cached
 from repro.kernels import ops
 from repro.resilience.faults import CorruptChunkError
 from repro.resilience.recovery import RetryPolicy, with_retries
+from repro.kernels.ref import PRECISION
 
 _NEG_INF = jnp.float32(-jnp.inf)
 _BIG_ID = jnp.int32(2**31 - 1)
@@ -582,7 +583,8 @@ def _sketch_bound(residual, r0, chunk_thresh, chunk_norm, chunk_cached,
     closed into the next rung is exact)."""
     r0n2 = jnp.sum(r0 * r0)
     r0n = jnp.sqrt(r0n2)
-    alpha = jnp.dot(residual, r0) / jnp.maximum(r0n2, 1e-30)
+    alpha = (jnp.dot(residual, r0, precision=PRECISION)
+             / jnp.maximum(r0n2, 1e-30))
     rperp = residual - alpha * r0
     rpn = jnp.sqrt(jnp.sum(rperp * rperp))
     fin = jnp.isfinite(chunk_thresh)
@@ -775,7 +777,7 @@ def _commit_rounds(buf_rows, buf_ids, buf_dead, indices, mask, weights,
                         maxv, avail_a, absolute=absolute)
                     return u_max, n_off
                 rnorm = jnp.sqrt(jnp.sum(residual * residual))
-                s = arf @ residual
+                s = jnp.dot(arf, residual, precision=PRECISION)
                 s = jnp.abs(s) if absolute else s
                 u = s + (ar_errn + acc * ar_norms) * rnorm
                 u_m = jnp.where(avail_a, u, _NEG_INF)
@@ -796,16 +798,17 @@ def _commit_rounds(buf_rows, buf_ids, buf_dead, indices, mask, weights,
             msk = mask.at[t].set(True)
             rws = rows.at[t].set(g_e)
             mask_p = msk[:p]
-            row_vals = jnp.where(mask_p, rws[:p] @ g_e, 0.0)
+            row_vals = jnp.where(
+                mask_p, jnp.dot(rws[:p], g_e, precision=PRECISION), 0.0)
             grm = gram.at[t, :p].set(row_vals).at[:p, t].set(row_vals)
             ar = jnp.where(mask_p, absrow[:p] + jnp.abs(row_vals), 0.0)
             ar = ar.at[t].set(jnp.sum(jnp.abs(row_vals)))
             arow = absrow.at[:p].set(ar)
-            tc = tcorr.at[t].set(jnp.dot(g_e, target))
+            tc = tcorr.at[t].set(jnp.dot(g_e, target, precision=PRECISION))
             w_p = _nnls_active_cached(grm[:p, :p], arow[:p], rws[:p],
                                       tc[:p], mask_p, lam, nnls_iters)
             w = jnp.zeros_like(weights).at[:p].set(w_p)
-            resid = target - w_p @ rws[:p]
+            resid = target - jnp.dot(w_p, rws[:p], precision=PRECISION)
             er = jnp.sum(resid**2) + lam * jnp.sum(w_p**2)
             tk = (ar_taken.at[pick_pos(e)].set(True, mode="drop")
                   if has_arena else ar_taken)
@@ -822,7 +825,7 @@ def _commit_rounds(buf_rows, buf_ids, buf_dead, indices, mask, weights,
                 # Runs once per loop exit: a transient conversion here is
                 # fine on the fused-kernel path (no persistent f32 copy).
                 rows_f = arf if use_ref else ar_rows.astype(jnp.float32)
-                s = rows_f @ residual
+                s = jnp.dot(rows_f, residual, precision=PRECISION)
                 s = jnp.abs(s) if absolute else s
                 u = s + (ar_errn + acc * ar_norms) * rnorm
                 u_m = jnp.where(avail_a, u, _NEG_INF)

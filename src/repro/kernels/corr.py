@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import PRECISION
+
 TILE_N = 128   # rows per grid step (MXU sublane-aligned)
 TILE_D = 512   # proxy-dim chunk per grid step (lane-aligned, 128 | TILE_D)
 
@@ -36,7 +38,7 @@ def _corr_kernel(g_ref, r_ref, out_ref):
     j = pl.program_id(1)
     g = g_ref[...].astype(jnp.float32)          # (TILE_N, TILE_D)
     r = r_ref[...].astype(jnp.float32)          # (TILE_D, 1)
-    partial = g @ r                             # (TILE_N, 1)  -- MXU matvec
+    partial = jnp.dot(g, r, precision=PRECISION)  # (TILE_N, 1) -- MXU
 
     @pl.when(j == 0)
     def _init():
@@ -97,7 +99,7 @@ def _bound_max_kernel(g_ref, nrm_ref, err_ref, r_ref, sc_ref, mask_ref,
 
     g = g_ref[...].astype(jnp.float32)          # (TILE_N, TILE_D)
     r = r_ref[...].astype(jnp.float32)          # (TILE_D, 1)
-    acc_ref[...] += g @ r
+    acc_ref[...] += jnp.dot(g, r, precision=PRECISION)
 
     @pl.when(j == last_j)
     def _reduce():
@@ -201,7 +203,7 @@ def _corr_argmax_kernel(c_ref, w_ref, base_ref, mask_ref, idx_ref, val_ref,
 
     c = c_ref[...].astype(jnp.float32)          # (TILE_N, TILE_D)
     w = w_ref[...].astype(jnp.float32)          # (TILE_D, 1)
-    acc_ref[...] += c @ w                       # (TILE_N, 1)  -- MXU matvec
+    acc_ref[...] += jnp.dot(c, w, precision=PRECISION)  # (TILE_N, 1)
 
     @pl.when(j == last_j)
     def _reduce():

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import PRECISION
+
 TILE_I = 128   # coverage-row tile (sublane-aligned)
 TILE_J = 128   # candidate-column tile (lane-aligned)
 TILE_D = 512   # proxy-dim chunk for the on-the-fly inner product
@@ -149,7 +151,7 @@ def _fl_gain_otf_kernel(gr_ref, gc_ref, rn_ref, cn_ref, cover_ref, rok_ref,
 
     a = gr_ref[...].astype(jnp.float32)           # (TILE_I, TILE_D) rows
     b = gc_ref[...].astype(jnp.float32)           # (TILE_J, TILE_D) cands
-    dot_ref[...] += a @ b.T                       # (TILE_I, TILE_J) — MXU
+    dot_ref[...] += jnp.dot(a, b.T, precision=PRECISION)  # (TILE_I, TILE_J)
 
     @pl.when(kd == last_kd)
     def _accumulate():

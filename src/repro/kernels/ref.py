@@ -11,10 +11,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Selection contractions are f32-exact on every backend: left to its
+# default, XLA on a TPU rounds f32 matmul operands to bf16 (DESIGN §1).
+PRECISION = jax.lax.Precision.HIGHEST
+
 
 def corr_ref(grads: jax.Array, residual: jax.Array) -> jax.Array:
     """OMP residual-correlation scores:  (n, d) @ (d,) -> (n,) in f32."""
-    return grads.astype(jnp.float32) @ residual.astype(jnp.float32)
+    return jnp.dot(grads.astype(jnp.float32), residual.astype(jnp.float32),
+                   precision=PRECISION)
 
 
 def corr_argmax_ref(colcache: jax.Array, w: jax.Array, base: jax.Array,
@@ -26,8 +31,9 @@ def corr_argmax_ref(colcache: jax.Array, w: jax.Array, base: jax.Array,
     (argmax index i32 (), max score f32 ()).  Ties resolve to the lowest
     index (jnp.argmax semantics); an all-False mask yields (0, -inf).
     """
-    scores = base.astype(jnp.float32) - (
-        colcache.astype(jnp.float32) @ w.astype(jnp.float32))
+    scores = base.astype(jnp.float32) - jnp.dot(
+        colcache.astype(jnp.float32), w.astype(jnp.float32),
+        precision=PRECISION)
     if absolute:
         scores = jnp.abs(scores)
     scores = jnp.where(mask, scores, -jnp.inf)
@@ -44,7 +50,8 @@ def corr_batched_ref(grads: jax.Array, vecs: jax.Array) -> jax.Array:
     contiguous rows (``g @ v^T``) runs ~2x faster on XLA:CPU than
     ``v @ g^T`` and feeds an axis-0 argmax with no output transpose.
     """
-    return grads.astype(jnp.float32) @ vecs.astype(jnp.float32).T
+    return jnp.dot(grads.astype(jnp.float32), vecs.astype(jnp.float32).T,
+                   precision=PRECISION)
 
 
 def corr_argmax_batched_ref(mat: jax.Array, w: jax.Array, base_t: jax.Array,
@@ -64,10 +71,12 @@ def corr_argmax_batched_ref(mat: jax.Array, w: jax.Array, base_t: jax.Array,
     w = w.astype(jnp.float32)
     base_t = base_t.astype(jnp.float32)
     if mat.ndim == 2:
-        scores = base_t - mat.astype(jnp.float32) @ w.T        # (n, B)
+        scores = base_t - jnp.dot(mat.astype(jnp.float32), w.T,
+                                  precision=PRECISION)          # (n, B)
     else:
         scores = base_t - jnp.einsum("bnp,bp->nb",
-                                     mat.astype(jnp.float32), w)
+                                     mat.astype(jnp.float32), w,
+                                     precision=PRECISION)
     if absolute:
         scores = jnp.abs(scores)
     scores = jnp.where(mask_t, scores, -jnp.inf)
@@ -95,7 +104,7 @@ def bound_max_ref(rows: jax.Array, norms: jax.Array, errn: jax.Array,
     yields (-inf, 0, 0).
     """
     r = residual.astype(jnp.float32)
-    s = rows.astype(jnp.float32) @ r
+    s = jnp.dot(rows.astype(jnp.float32), r, precision=PRECISION)
     if absolute:
         s = jnp.abs(s)
     rnorm = jnp.sqrt(jnp.sum(r * r))
@@ -157,7 +166,8 @@ def fl_gains_cols_ref(cand: jax.Array, cand_sqn: jax.Array,
         rn = jax.lax.dynamic_slice(sqnp, (lo,), (block,))
         cv = jax.lax.dynamic_slice(cp, (lo,), (block,))
         ok = jax.lax.dynamic_slice(okp, (lo,), (block,))
-        d2 = rn[:, None] + cand_sqn[None, :] - 2.0 * (rows @ cand.T)
+        d2 = rn[:, None] + cand_sqn[None, :] - 2.0 * jnp.dot(
+            rows, cand.T, precision=PRECISION)
         s = (lm - jnp.sqrt(jnp.maximum(d2, 0.0))) * ok[:, None]
         return gains + jnp.sum(jnp.maximum(s - cv[:, None], 0.0), axis=0)
 
@@ -203,7 +213,8 @@ def sqdist_ref(a: jax.Array, b: jax.Array) -> jax.Array:
     b = b.astype(jnp.float32)
     an = jnp.sum(a * a, axis=-1)
     bn = jnp.sum(b * b, axis=-1)
-    d2 = an[:, None] + bn[None, :] - 2.0 * (a @ b.T)
+    d2 = an[:, None] + bn[None, :] - 2.0 * jnp.dot(a, b.T,
+                                                 precision=PRECISION)
     return jnp.maximum(d2, 0.0)
 
 

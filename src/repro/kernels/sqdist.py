@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import PRECISION
+
 TILE_M = 128
 TILE_N = 128
 TILE_D = 512
@@ -28,7 +30,7 @@ def _sqdist_kernel(a_ref, b_ref, an_ref, bn_ref, out_ref, *, n_dchunks):
     k = pl.program_id(2)
     a = a_ref[...].astype(jnp.float32)           # (TILE_M, TILE_D)
     b = b_ref[...].astype(jnp.float32)           # (TILE_N, TILE_D)
-    partial = a @ b.T                            # (TILE_M, TILE_N)
+    partial = jnp.dot(a, b.T, precision=PRECISION)  # (TILE_M, TILE_N)
 
     @pl.when(k == 0)
     def _init():

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.artifacts import ArtifactStore, build_artifact
 from repro.core.omp import omp_select, omp_session_start
+from repro.launch.cache import enable_compile_cache
 from repro.serve.registry import PoolRegistry
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
@@ -118,6 +119,7 @@ def main(argv=None) -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="small pools + differential self-check (CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         args.pools = min(args.pools, 2)
         args.pool_size = min(args.pool_size, 512)

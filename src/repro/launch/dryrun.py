@@ -1,5 +1,9 @@
 import os
+# CPU-only by design: 512 placeholder host devices, pinned to the CPU
+# platform so that neither this process nor the per-cell children it
+# spawns ever takes an accelerator.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -41,6 +45,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.distributed import hints
 from repro.distributed.sharding import (logical_rules, param_shardings)
 from repro.launch import specs as specs_lib
+from repro.launch.cache import enable_compile_cache
 from repro.launch.hlo_analysis import (collective_bytes,
                                        collective_bytes_weighted,
                                        roofline_terms)
@@ -358,6 +363,7 @@ def main() -> None:
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.all:
         from repro.configs import ARCH_IDS

@@ -26,9 +26,16 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """Small mesh over whatever local devices exist (tests/examples)."""
+    """(data, model) mesh over the first ``data * model`` local devices.
+
+    Raises when fewer devices exist than the mesh asks for: a mesh that
+    shrank to what is present would run a different program than the one
+    requested and still pass.
+    """
     n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, max(n // data, 1))
+    if data < 1 or model < 1 or data * model > n:
+        raise ValueError(
+            f"a data={data} x model={model} mesh needs {data * model} "
+            f"devices; {n} present")
     return jax.make_mesh((data, model), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)

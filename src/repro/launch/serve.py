@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm as lm_lib
 
 
@@ -33,6 +34,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))

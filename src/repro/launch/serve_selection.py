@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.omp import omp_select
+from repro.launch.cache import enable_compile_cache
 from repro.serve import SelectionService
 
 
@@ -62,6 +63,7 @@ def main(argv=None) -> dict:
                     help="transient fault rate on the chunked pool "
                          "(--load)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         args.pool_size = min(args.pool_size, 1024)
         args.k = min(args.k, 64)

@@ -21,6 +21,7 @@ gated up-projection FFN.  Decode for both is the O(1) single-step recurrence.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -33,6 +34,22 @@ from repro.models.common import dtype_of
 from repro.models.ssm import _causal_conv
 
 _M_CLAMP = 60.0  # exp(60) ~ 1e26: safe in f32
+
+# mLSTM input-gate bias at init, as in xLSTM 7B (Beck et al., 2025).  At
+# 0 the normaliser |q.n| sits near its floor exp(-m) and passes it at a
+# third of the positions, where h is a ratio of two linear forms in q; the
+# backward then grows about 10x per 8-block super-block.  On a CPU, the
+# xlstm-1.3b stack (48 blocks) cut to width 512 had a step-0 gradient
+# norm of 1.4e7 at 0 and 20 at -10 (8 blocks: 9): the floor, about e^10,
+# then dominates and h is linear in q, k and v.
+_B_I_INIT = -10.0
+
+# The cells' f32 contractions (the only f32 x f32 ones in the model) run
+# f32-exact.  At a TPU's default precision their operands are rounded to
+# bf16: on full-depth xlstm-1.3b, with the input-gate bias below at 0,
+# that made the step-0 gradient norm 28x what it is with exact products
+# (1.7e5 against 6.1e3 on a TPU v5e).
+_einsum = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
 
 
 def mlstm_dims(cfg: ModelConfig) -> tuple[int, int, int, int]:
@@ -63,7 +80,7 @@ def init_mlstm(cfg: ModelConfig, key: jax.Array) -> dict:
         "wk": common.dense_init(ks[4], (d_in, qk), dt, fan_in=d_in),
         "wi_gate": common.dense_init(ks[5], (d_in, h), dt, fan_in=d_in),
         "wf_gate": common.dense_init(ks[6], (d_in, h), dt, fan_in=d_in),
-        "b_i": jnp.zeros((h,), jnp.float32),
+        "b_i": jnp.full((h,), _B_I_INIT, jnp.float32),
         "b_f": jnp.full((h,), 3.0, jnp.float32),   # start remembering
         "head_norm": jnp.ones((d_in,), jnp.float32),
         "down_proj": common.dense_init(
@@ -106,23 +123,23 @@ def _mlstm_chunk_scan(q, k, v, ig, fg, chunk, state):
             - g.transpose(0, 2, 1)[:, :, :, None]
         )
         dmat = jnp.where(smask[None, None], dmat, 0.0)
-        scores = jnp.einsum("bthk,bshk->bhts", qi, ki)
+        scores = _einsum("bthk,bshk->bhts", qi, ki)
         wmat = scores * dmat
 
-        num = jnp.einsum("bhts,bshd->bthd", wmat, vi)
-        num = num + carry_coef[..., None] * jnp.einsum(
+        num = _einsum("bhts,bshd->bthd", wmat, vi)
+        num = num + carry_coef[..., None] * _einsum(
             "bthk,bhkd->bthd", qi, c_prev)
-        den = jnp.einsum("bhts->bth", wmat)
-        den = den + carry_coef * jnp.einsum("bthk,bhk->bth", qi, n_prev)
+        den = _einsum("bhts->bth", wmat)
+        den = den + carry_coef * _einsum("bthk,bhk->bth", qi, n_prev)
         floor = jnp.exp(jnp.minimum(-m_t, _M_CLAMP))
         hout = num / jnp.maximum(jnp.abs(den), floor)[..., None]
 
         g_end = g[:, -1]                               # (B,H)
         u_end = jnp.exp(ib - g_end[:, None, :])        # (B,Q,H) <= 1
         coef = jnp.exp(m_prev - g_end)
-        c_new = coef[..., None, None] * c_prev + jnp.einsum(
+        c_new = coef[..., None, None] * c_prev + _einsum(
             "bqh,bqhk,bqhd->bhkd", u_end, ki, vi)
-        n_new = coef[..., None] * n_prev + jnp.einsum(
+        n_new = coef[..., None] * n_prev + _einsum(
             "bqh,bqhk->bhk", u_end, ki)
         m_new = bcum[:, -1] + g_end
         return (c_new, n_new, m_new), hout
@@ -176,9 +193,9 @@ def _mlstm_chunkwise_parallel(q, k, v, ig, fg, chunk, state):
     # combine phase agree — without it GSPMD reshards (B,NC,H,dk,dv)
     # between phases every layer (§Roofline: the xlstm train outlier).
     u_c = hints.constrain(
-        jnp.einsum("bcqh,bcqhk,bcqhd->bchkd", u_p, ks_, vs),
+        _einsum("bcqh,bcqhk,bcqhd->bchkd", u_p, ks_, vs),
         "mlstm_chunk_state")
-    nu_c = jnp.einsum("bcqh,bcqhk->bchk", u_p, ks_)
+    nu_c = _einsum("bcqh,bcqhk->bchk", u_p, ks_)
 
     # Intra-chunk attention-like part relative to a_t (row max).
     smask = jnp.arange(qc)[:, None] >= jnp.arange(qc)[None, :]
@@ -187,9 +204,9 @@ def _mlstm_chunkwise_parallel(q, k, v, ig, fg, chunk, state):
         - a.transpose(0, 1, 3, 2)[:, :, :, :, None]      # a_t   (B,NC,H,Q,1)
     )
     dmat_p = jnp.where(smask[None, None, None], dmat_p, 0.0)
-    scores = jnp.einsum("bcthk,bcshk->bchts", qs, ks_)
+    scores = _einsum("bcthk,bcshk->bchts", qs, ks_)
     wmat = scores * dmat_p                          # (B,NC,H,Q,Q)
-    intra_num = jnp.einsum("bchts,bcshd->bcthd", wmat, vs)
+    intra_num = _einsum("bchts,bcshd->bcthd", wmat, vs)
     intra_den = jnp.sum(wmat, axis=-1)              # (B,NC,H,Q)
     intra_den = intra_den.transpose(0, 1, 3, 2)     # (B,NC,Q,H)
 
@@ -218,9 +235,9 @@ def _mlstm_chunkwise_parallel(q, k, v, ig, fg, chunk, state):
     m_t = bcum + g
     r = jnp.exp(a - g)                              # row rescale <= 1
     carry_coef = jnp.exp(m_prevs[:, :, None] - g)   # (B,NC,Q,H)
-    inter_num = jnp.einsum("bcqhk,bchkd->bcqhd", qs, c_prevs)
+    inter_num = _einsum("bcqhk,bchkd->bcqhd", qs, c_prevs)
     num = r[..., None] * intra_num + carry_coef[..., None] * inter_num
-    inter_den = jnp.einsum("bcqhk,bchk->bcqh", qs, n_prevs)
+    inter_den = _einsum("bcqhk,bchk->bcqh", qs, n_prevs)
     den = r * intra_den + carry_coef * inter_den
     floor = jnp.exp(jnp.minimum(-m_t, _M_CLAMP))
     hout = num / jnp.maximum(jnp.abs(den), floor)[..., None]
@@ -295,8 +312,8 @@ def mlstm_decode(cfg: ModelConfig, p: dict, x: jax.Array, state: dict):
     c_new = coef_f[..., None, None] * state["C"] + coef_i[..., None, None] \
         * (k1[..., :, None] * v1[..., None, :])
     n_new = coef_f[..., None] * state["n"] + coef_i[..., None] * k1
-    num = jnp.einsum("bhk,bhkd->bhd", q1, c_new)
-    den = jnp.einsum("bhk,bhk->bh", q1, n_new)
+    num = _einsum("bhk,bhkd->bhd", q1, c_new)
+    den = _einsum("bhk,bhk->bh", q1, n_new)
     floor = jnp.exp(jnp.minimum(-m_new, _M_CLAMP))
     h = (num / jnp.maximum(jnp.abs(den), floor)[..., None])[:, None]
     y = _head_norm_gate(p, h, z, x.dtype) @ p["down_proj"]
@@ -348,7 +365,7 @@ def _slstm_cell(cfg, p, xz, xi, xf, xo, state):
                                  cfg.d_model // cfg.n_heads)
 
     def rec(w):
-        return jnp.einsum("bhd,hde->bhe", h_heads,
+        return _einsum("bhd,hde->bhe", h_heads,
                           w.astype(jnp.float32)).reshape(state["h"].shape)
 
     z = jnp.tanh(xz + rec(p["r_z"]) + p["b_z"])

@@ -35,7 +35,7 @@ def _lr_at(lr: Schedule, step: jax.Array) -> jax.Array:
 
 def _f32_like(params: Any) -> Any:
     return jax.tree_util.tree_map(
-        lambda p: jnp.zeros(p.shape, jnp.float32), params)
+        lambda p: jnp.zeros_like(p, jnp.float32), params)
 
 
 def global_norm(tree: Any) -> jax.Array:

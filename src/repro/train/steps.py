@@ -19,7 +19,7 @@ from repro.configs.base import ModelConfig
 from repro.configs.paper import ClassifierConfig
 from repro.models import classifier as clf_lib
 from repro.models import lm as lm_lib
-from repro.optim import Optimizer, apply_updates
+from repro.optim import Optimizer, apply_updates, global_norm
 from repro.train import compression as comp_lib
 
 
@@ -123,6 +123,7 @@ def lm_train_step_fn(
 
     def step(params, opt_state, batch):
         grads, metrics = grads_of(params, batch)
+        metrics = {**metrics, "grad_norm": global_norm(grads)}
         updates, opt_state = opt.update(grads, opt_state, params)
         params = apply_updates(params, updates)
         return params, opt_state, metrics

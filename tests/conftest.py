@@ -18,9 +18,10 @@ def _bounded_compile_cache():
     The suite compiles thousands of distinct (function, shape) programs;
     XLA:CPU keeps every live executable mapped and segfaults inside
     ``backend_compile`` once enough of them accumulate in one process
-    (observed deterministically at the suite's tail on jaxlib 0.4.36).
-    Modules are independent — each recompiles its own shapes on entry —
-    so clearing per module bounds the live-executable count without
-    changing any test's behavior."""
+    (seen at the suite's tail on jaxlib 0.4.36; jaxlib 0.9.0 has not
+    been shown to need the bound, which costs only the recompiles
+    below, so it stays).  Modules are independent — each recompiles its
+    own shapes on entry — so clearing per module bounds the
+    live-executable count without changing any test's behavior."""
     yield
     jax.clear_caches()

@@ -12,11 +12,6 @@ import textwrap
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-if not hasattr(jax.sharding, "AxisType"):  # repro.launch.mesh needs it
-    pytest.skip("requires jax.sharding.AxisType (newer jax)",
-                allow_module_level=True)
 
 from repro.core.distributed import sharded_gradmatch_pb, sharded_omp_select
 from repro.core.omp import omp_select

@@ -3,11 +3,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-if not hasattr(jax.sharding, "AxisType"):  # repro.launch.mesh needs it
-    pytest.skip("requires jax.sharding.AxisType (newer jax)",
-                allow_module_level=True)
 
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.configs import get_smoke_config

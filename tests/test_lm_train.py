@@ -4,9 +4,7 @@ weighted objective, smoke-train convergence, xLSTM equivalence."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_smoke_config

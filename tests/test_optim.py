@@ -102,3 +102,18 @@ def test_step_counter_advances():
     for i in range(3):
         _, s = opt.update({"w": jnp.ones((2,))}, s, p)
     assert int(s.step) == 3
+
+
+@pytest.mark.parametrize("make", [lambda: sgd(0.1, momentum=0.9),
+                                  lambda: adamw(0.1)])
+def test_state_slots_take_the_parameter_sharding(make):
+    """f32 slots are created where their parameter lives: a sharded
+    parameter never has its whole slot on one device."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    mesh = jax.make_mesh((1,), ("data",))
+    sh = NamedSharding(mesh, PartitionSpec("data"))
+    p = {"w": jax.device_put(jnp.ones((8,), jnp.bfloat16), sh)}
+    for slot in jax.tree_util.tree_leaves(make().init(p).slots):
+        assert slot.sharding == sh
+        assert slot.dtype == jnp.float32

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
