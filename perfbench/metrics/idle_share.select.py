@@ -1,0 +1,10 @@
+"""Device idle share of the library loop's window, from the trace: per
+cent of the traced window in which no operation ran on the device
+(averaged over the cell's chips)."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if tr is None or tr.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - tr.busy_s / tr.window_s)
