@@ -57,6 +57,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.core import gradmatch as gm_lib  # noqa: E402
 from repro.core import partition as part_lib  # noqa: E402
 from repro.core import selection as sel_lib  # noqa: E402
@@ -80,8 +81,6 @@ PARTITIONED = dict(n=8192, d=512, k=512, parts=4, seed=0)
 OBJECTIVE_BAND = 0.01     # |err_chip / err_reference - 1|
 REFERENCE_TIMEOUT_S = 900
 
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-
 
 class SmokeFailure(RuntimeError):
     """A check of what the chip computed did not hold."""
@@ -97,24 +96,17 @@ def raise_if(failures: list, phase: str) -> None:
         raise SmokeFailure(f"{phase}: " + "; ".join(failures))
 
 
-class CompileClock:
-    """Sums XLA backend compile time (persistent-cache reads included)
-    as JAX reports it, so each phase can split compile from run time."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event: str, secs: float, **_) -> None:
-        if event == _BACKEND_COMPILE:
-            self.seconds += secs
+def compile_seconds() -> float:
+    """XLA backend compile seconds so far in this process, persistent-cache
+    reads included, as JAX reports them (``repro.obs``)."""
+    return obs.counters().get("jax.compile_s", 0.0)
 
 
-def run_phase(name: str, fn, clock: CompileClock):
-    t0, c0 = time.perf_counter(), clock.seconds
+def run_phase(name: str, fn):
+    t0, c0 = time.perf_counter(), compile_seconds()
     out = fn()
     wall = time.perf_counter() - t0
-    compile_s = clock.seconds - c0
+    compile_s = compile_seconds() - c0
     print(f"[phase] {name}: wall_s={wall:.2f} compile_s={compile_s:.2f} "
           f"run_s={wall - compile_s:.2f}", flush=True)
     return out
@@ -472,17 +464,16 @@ def main(argv=None) -> int:
         cpu_reference_main(json.loads(args.cpu_reference))
         return 0
 
-    clock = CompileClock()
     count = FSDP_TRAIN["data"] if args.four_chips else 1
-    dev = run_phase("device", lambda: check_device(count), clock)
+    dev = run_phase("device", lambda: check_device(count))
     if args.four_chips:
-        run_phase("fsdp-train", phase_fsdp_train, clock)
-        run_phase("partitioned", phase_partitioned, clock)
+        run_phase("fsdp-train", phase_fsdp_train)
+        run_phase("partitioned", phase_partitioned)
     else:
-        run_phase("selection", phase_selection, clock)
-        run_phase("serving", phase_serving, clock)
-        run_phase("lm-train", phase_lm_train, clock)
-        run_phase("trainer", phase_trainer, clock)
+        run_phase("selection", phase_selection)
+        run_phase("serving", phase_serving)
+        run_phase("lm-train", phase_lm_train)
+        run_phase("trainer", phase_trainer)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}), flush=True)

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import omp as omp_lib
 from repro.core import proxies as proxy_lib
 from repro.kernels.ref import PRECISION
@@ -91,18 +92,23 @@ def gradmatch_per_class(
     ``||Σ_c g_tgt_c − Σ w·g||² + λ||w||²`` of the unnormalized per-class
     solution against the summed target — not a placeholder.
     """
-    labels_np = np.asarray(labels)
-    in_range = (labels_np >= 0) & (labels_np < num_classes)
-    sizes = np.bincount(labels_np[in_range], minlength=num_classes)
-    quotas = omp_lib.split_budget(k, sizes)
-    onehot = jax.nn.one_hot(labels, num_classes, dtype=grads.dtype)  # (n, C)
-    targets = jnp.dot(onehot.T, grads, precision=PRECISION)          # (C, d)
-    idx, w, mask = omp_lib.omp_select_per_class(
-        grads, labels, targets, num_classes, 0, lam=lam, eps=eps,
-        method=method, quotas=quotas,
-    )
-    err = omp_lib.matching_error(grads, jnp.sum(targets, axis=0), idx, w,
-                                 mask, lam=lam)
+    with obs.span("gradmatch.budget"):
+        labels_np = np.asarray(labels)
+        in_range = (labels_np >= 0) & (labels_np < num_classes)
+        sizes = np.bincount(labels_np[in_range], minlength=num_classes)
+        quotas = omp_lib.split_budget(k, sizes)
+    with obs.span("gradmatch.targets"):
+        onehot = jax.nn.one_hot(labels, num_classes,
+                                dtype=grads.dtype)                   # (n, C)
+        targets = jnp.dot(onehot.T, grads, precision=PRECISION)      # (C, d)
+    with obs.span("omp.per_class"):
+        idx, w, mask = omp_lib.omp_select_per_class(
+            grads, labels, targets, num_classes, 0, lam=lam, eps=eps,
+            method=method, quotas=quotas,
+        )
+    with obs.span("gradmatch.err"):
+        err = omp_lib.matching_error(grads, jnp.sum(targets, axis=0), idx,
+                                     w, mask, lam=lam)
     # Per-class weights each sum to ~their class share; renormalize globally.
     return SelectionResult(idx, _normalize(w, mask), mask, err)
 

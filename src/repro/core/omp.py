@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import obs
 from repro.kernels import ops
 from repro.kernels.ref import PRECISION
 
@@ -178,35 +179,38 @@ def _omp_select_dense(grads, target, k, lam, eps, nnls_iters, positive,
 
     def body(t, state: OMPState):
         # 1) residual correlations;  already-selected / invalid candidates out.
-        scores = correlate(state.residual)
-        if positive:
-            scores_sel = scores          # match direction of the target
-        else:
-            scores_sel = jnp.abs(scores)
-        # Unused slots point at the out-of-bounds sentinel n so mode="drop"
-        # discards them (an in-bounds sentinel would race duplicate writes).
-        taken = jnp.zeros((n,), dtype=bool).at[
-            jnp.where(state.mask, state.indices, n)
-        ].set(state.mask, mode="drop")
-        scores_sel = jnp.where(valid & ~taken, scores_sel, neg_inf)
-        e = jnp.argmax(scores_sel).astype(jnp.int32)
+        with obs.scope("omp.score"):
+            scores = correlate(state.residual)
+            if positive:
+                scores_sel = scores          # match direction of the target
+            else:
+                scores_sel = jnp.abs(scores)
+            # Unused slots point at the out-of-bounds sentinel n so
+            # mode="drop" discards them (an in-bounds sentinel would race
+            # duplicate writes).
+            taken = jnp.zeros((n,), dtype=bool).at[
+                jnp.where(state.mask, state.indices, n)
+            ].set(state.mask, mode="drop")
+            scores_sel = jnp.where(valid & ~taken, scores_sel, neg_inf)
+            e = jnp.argmax(scores_sel).astype(jnp.int32)
 
         # stop criterion E_lambda <= eps  -> do not grow the active set.
-        grow = state.err > eps
-        new_indices = state.indices.at[t].set(jnp.where(grow, e, -1))
-        new_mask = state.mask.at[t].set(grow)
+        with obs.scope("omp.column"):
+            grow = state.err > eps
+            new_indices = state.indices.at[t].set(jnp.where(grow, e, -1))
+            new_mask = state.mask.at[t].set(grow)
+            # 2) gather the active set and rebuild its Gram.
+            sel = jnp.where(new_mask, new_indices, 0)
+            g_s = grads[sel] * new_mask[:, None].astype(grads.dtype)  # (k, d)
+            gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
+            corr = jnp.dot(g_s, target, precision=PRECISION)
 
-        # 2) re-solve non-negative ridge LS on the active set.
-        sel = jnp.where(new_mask, new_indices, 0)
-        g_s = grads[sel] * new_mask[:, None].astype(grads.dtype)  # (k, d)
-        gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
-        corr = jnp.dot(g_s, target, precision=PRECISION)
-        w = _nnls_active(gram, corr, new_mask, lam, nnls_iters)
-
-        # 3) residual + error refresh.
-        approx = jnp.dot(w, g_s, precision=PRECISION)
-        residual = target - approx
-        err = jnp.sum(residual**2) + lam * jnp.sum(w**2)
+        # 3) re-solve non-negative ridge LS; residual + error refresh.
+        with obs.scope("omp.nnls"):
+            w = _nnls_active(gram, corr, new_mask, lam, nnls_iters)
+            approx = jnp.dot(w, g_s, precision=PRECISION)
+            residual = target - approx
+            err = jnp.sum(residual**2) + lam * jnp.sum(w**2)
         return OMPState(new_indices, new_mask, w, residual, err)
 
     init = OMPState(
@@ -260,54 +264,57 @@ def _inc_body_factory(grads, target, c0, valid, lam, eps, nnls_iters,
             p = st.weights.shape[0]     # static prefix width, t < p <= k
             # 1) fused scores-and-argmax (one streaming pass, no (n,)
             #    score vector materialized on TPU).
-            # Out-of-bounds sentinel for unused slots, dropped by the
-            # scatter — see the dense body for why n-1 would be wrong.
-            taken = jnp.zeros((n,), dtype=bool).at[
-                jnp.where(st.mask, st.indices, n)
-            ].set(st.mask, mode="drop")
-            avail = valid & ~taken
-            if use_cols:
-                e, _ = ops.corr_argmax(st.colcache, st.weights, c0, avail,
-                                       absolute=absolute)
-            else:
-                e, _ = ops.corr_argmax(grads, -st.residual, zeros_n, avail,
-                                       absolute=absolute)
-
-            # stop criterion E_lambda <= eps -> do not grow the active set.
-            grow = st.err > eps
-            growf = grow.astype(jnp.float32)
-            indices = st.indices.at[t].set(jnp.where(grow, e, -1))
-            mask = st.mask.at[t].set(grow)
-            mask_p = mask[:p]
+            with obs.scope("omp.score"):
+                # Out-of-bounds sentinel for unused slots, dropped by the
+                # scatter — see the dense body for why n-1 would be wrong.
+                taken = jnp.zeros((n,), dtype=bool).at[
+                    jnp.where(st.mask, st.indices, n)
+                ].set(st.mask, mode="drop")
+                avail = valid & ~taken
+                if use_cols:
+                    e, _ = ops.corr_argmax(st.colcache, st.weights, c0,
+                                           avail, absolute=absolute)
+                else:
+                    e, _ = ops.corr_argmax(grads, -st.residual, zeros_n,
+                                           avail, absolute=absolute)
 
             # 2) extend the caches by one slot (updates are gated on `grow`
             #    so a stopped solver leaves every buffer unchanged).
-            g_e = grads[e] * growf
-            rows = st.rows.at[t].set(g_e)
-            if use_cols:
-                # Single touch of G this round; the new Gram row is a free
-                # read out of the cache: A[t, j] = g_{e_t}.g_{e_j} = C[e, j].
-                colcache = st.colcache.at[:, t].set(ops.corr(grads, g_e))
-                row_vals = jnp.where(mask_p, colcache[e], 0.0) * growf
-            else:
-                colcache = st.colcache
-                row_vals = jnp.where(
-                    mask_p, jnp.dot(rows, g_e, precision=PRECISION), 0.0)
-            gram = st.gram.at[t, :].set(row_vals).at[:, t].set(row_vals)
-            # Gershgorin row sums pick up the new row/col in O(p).
-            absrow = jnp.where(mask_p, st.gram_absrow + jnp.abs(row_vals),
-                               0.0)
-            absrow = absrow.at[t].set(jnp.sum(jnp.abs(row_vals)))
-            tcorr = st.tcorr.at[t].set(c0[e] * growf)
+            with obs.scope("omp.column"):
+                # stop criterion E_lambda <= eps -> do not grow the set.
+                grow = st.err > eps
+                growf = grow.astype(jnp.float32)
+                indices = st.indices.at[t].set(jnp.where(grow, e, -1))
+                mask = st.mask.at[t].set(grow)
+                mask_p = mask[:p]
+                g_e = grads[e] * growf
+                rows = st.rows.at[t].set(g_e)
+                if use_cols:
+                    # Single touch of G this round; the new Gram row is a
+                    # free read out of the cache:
+                    # A[t, j] = g_{e_t}.g_{e_j} = C[e, j].
+                    colcache = st.colcache.at[:, t].set(ops.corr(grads, g_e))
+                    row_vals = jnp.where(mask_p, colcache[e], 0.0) * growf
+                else:
+                    colcache = st.colcache
+                    row_vals = jnp.where(
+                        mask_p, jnp.dot(rows, g_e, precision=PRECISION), 0.0)
+                gram = st.gram.at[t, :].set(row_vals).at[:, t].set(row_vals)
+                # Gershgorin row sums pick up the new row/col in O(p).
+                absrow = jnp.where(mask_p,
+                                   st.gram_absrow + jnp.abs(row_vals), 0.0)
+                absrow = absrow.at[t].set(jnp.sum(jnp.abs(row_vals)))
+                tcorr = st.tcorr.at[t].set(c0[e] * growf)
 
             # 3) NNLS on the cached active-set buffers.
-            w = _nnls_active_cached(gram, absrow, rows, tcorr, mask_p, lam,
-                                    nnls_iters)
-            # ||r||^2 = ||g_tgt||^2 - 2 w^T c_S + w^T A w, evaluated in the
-            # factored form over cached rows (immune to the cancellation
-            # the expanded form suffers near the eps-stop).
-            resid = target - jnp.dot(w, rows, precision=PRECISION)
-            err = jnp.sum(resid**2) + lam * jnp.sum(w**2)
+            with obs.scope("omp.nnls"):
+                w = _nnls_active_cached(gram, absrow, rows, tcorr, mask_p,
+                                        lam, nnls_iters)
+                # ||r||^2 = ||g_tgt||^2 - 2 w^T c_S + w^T A w, evaluated in
+                # the factored form over cached rows (immune to the
+                # cancellation the expanded form suffers near the eps-stop).
+                resid = target - jnp.dot(w, rows, precision=PRECISION)
+                err = jnp.sum(resid**2) + lam * jnp.sum(w**2)
             return OMPIncState(indices, mask, w, colcache, gram, absrow,
                                tcorr, rows, resid, err)
         return body
@@ -350,14 +357,16 @@ def _omp_select_incremental(grads, target, k, lam, eps, nnls_iters, positive,
     on TPU): the wide call is (C, w, c0), the narrow call is (G, -r, 0).
     """
     n, d = grads.shape
-    c0 = ops.corr(grads, target)        # (n,), computed exactly once
+    with obs.scope("omp.init"):
+        c0 = ops.corr(grads, target)        # (n,), computed exactly once
+        st = _empty_inc_state(k, n, d, target)
     make_body = _inc_body_factory(grads, target, c0, valid, lam, eps,
                                   nnls_iters, absolute=not positive)
-    st = _empty_inc_state(k, n, d, target)
     for lo in range(0, k, block):
         hi = min(lo + block, k)
         use_cols = hi <= d
-        st = _grow_prefix(st, hi, keep_cols=use_cols)
+        with obs.scope("omp.prefix"):
+            st = _grow_prefix(st, hi, keep_cols=use_cols)
         st = lax.fori_loop(lo, hi, make_body(use_cols), st)
     return st.indices, st.weights, st.mask, st.err
 
@@ -709,9 +718,10 @@ def _omp_select_batched_incremental(grads, targets, k, lam, eps, nnls_iters,
     bsz = targets.shape[0]
     # Pool-sized arrays live pool-major (n, B) — the orientation the
     # shared-operand scan matmul produces natively (see kernels/ref.py).
-    c0_t = ops.corr_batched(grads, targets)        # (n, B), exactly once
-    zeros_nb = jnp.zeros((n, bsz), dtype=jnp.float32)
-    valids_t = valids.T                            # (n, B), hoisted
+    with obs.scope("omp.init"):
+        c0_t = ops.corr_batched(grads, targets)    # (n, B), exactly once
+        zeros_nb = jnp.zeros((n, bsz), dtype=jnp.float32)
+        valids_t = valids.T                        # (n, B), hoisted
     bcol = jnp.arange(bsz, dtype=jnp.int32)
     bcols_k = jnp.broadcast_to(bcol[:, None], (bsz, k))
     absolute = not positive
@@ -728,44 +738,50 @@ def _omp_select_batched_incremental(grads, targets, k, lam, eps, nnls_iters,
     def make_body(use_cols: bool):
         def body(t, st: OMPBatchState):
             p = st.weights.shape[1]
-            avail_t = valids_t & ~scatter_taken_t(st.mask, st.indices)
-            if use_cols:
-                e, _ = ops.corr_argmax_batched(st.colcache, st.weights,
-                                               c0_t, avail_t,
-                                               absolute=absolute)
-            else:
-                e, _ = ops.corr_argmax_batched(grads, -st.residual,
-                                               zeros_nb, avail_t,
-                                               absolute=absolute)
+            with obs.scope("omp.score"):
+                avail_t = valids_t & ~scatter_taken_t(st.mask, st.indices)
+                if use_cols:
+                    e, _ = ops.corr_argmax_batched(st.colcache, st.weights,
+                                                   c0_t, avail_t,
+                                                   absolute=absolute)
+                else:
+                    e, _ = ops.corr_argmax_batched(grads, -st.residual,
+                                                   zeros_nb, avail_t,
+                                                   absolute=absolute)
 
-            grow = st.err > eps                            # (B,)
-            growf = grow.astype(jnp.float32)
-            indices = st.indices.at[:, t].set(jnp.where(grow, e, -1))
-            mask = st.mask.at[:, t].set(grow)
-            mask_p = mask[:, :p]
+            with obs.scope("omp.column"):
+                grow = st.err > eps                            # (B,)
+                growf = grow.astype(jnp.float32)
+                indices = st.indices.at[:, t].set(jnp.where(grow, e, -1))
+                mask = st.mask.at[:, t].set(grow)
+                mask_p = mask[:, :p]
 
-            g_e = grads[e] * growf[:, None]                # (B, d)
-            rows = st.rows.at[:, t].set(g_e)
-            if use_cols:
-                newcol = ops.corr_batched(grads, g_e)      # (n, B)
-                colcache = st.colcache.at[:, :, t].set(newcol.T)
-                row_vals = jnp.where(mask_p, take_b(colcache, e),
-                                     0.0) * growf[:, None]
-            else:
-                colcache = st.colcache
-                row_vals = jnp.where(
-                    mask_p, jnp.einsum("bpd,bd->bp", rows, g_e,
-                                       precision=PRECISION), 0.0)
-            gram = st.gram.at[:, t, :].set(row_vals).at[:, :, t].set(row_vals)
-            absrow = jnp.where(mask_p,
-                               st.gram_absrow + jnp.abs(row_vals), 0.0)
-            absrow = absrow.at[:, t].set(jnp.sum(jnp.abs(row_vals), axis=1))
-            tcorr = st.tcorr.at[:, t].set(c0_t[e, bcol] * growf)
+                g_e = grads[e] * growf[:, None]                # (B, d)
+                rows = st.rows.at[:, t].set(g_e)
+                if use_cols:
+                    newcol = ops.corr_batched(grads, g_e)      # (n, B)
+                    colcache = st.colcache.at[:, :, t].set(newcol.T)
+                    row_vals = jnp.where(mask_p, take_b(colcache, e),
+                                         0.0) * growf[:, None]
+                else:
+                    colcache = st.colcache
+                    row_vals = jnp.where(
+                        mask_p, jnp.einsum("bpd,bd->bp", rows, g_e,
+                                           precision=PRECISION), 0.0)
+                gram = st.gram.at[:, t, :].set(row_vals).at[:, :, t].set(
+                    row_vals)
+                absrow = jnp.where(mask_p,
+                                   st.gram_absrow + jnp.abs(row_vals), 0.0)
+                absrow = absrow.at[:, t].set(jnp.sum(jnp.abs(row_vals),
+                                                     axis=1))
+                tcorr = st.tcorr.at[:, t].set(c0_t[e, bcol] * growf)
 
-            w = nnls_b(gram, absrow, rows, tcorr, mask_p, lam, nnls_iters)
-            resid = targets - jnp.einsum("bp,bpd->bd", w, rows,
-                                         precision=PRECISION)
-            err = jnp.sum(resid**2, axis=1) + lam * jnp.sum(w**2, axis=1)
+            with obs.scope("omp.nnls"):
+                w = nnls_b(gram, absrow, rows, tcorr, mask_p, lam,
+                           nnls_iters)
+                resid = targets - jnp.einsum("bp,bpd->bd", w, rows,
+                                             precision=PRECISION)
+                err = jnp.sum(resid**2, axis=1) + lam * jnp.sum(w**2, axis=1)
             return OMPBatchState(indices, mask, w, colcache, gram, absrow,
                                  tcorr, rows, resid, err)
         return body
@@ -791,7 +807,8 @@ def _omp_select_batched_incremental(grads, targets, k, lam, eps, nnls_iters,
         # Same math either way (scores are c0 - C@w == G@r); only the
         # reduction shapes differ, below the index-parity noise floor.
         use_cols = hi * bsz <= d
-        st = _grow_prefix_batched(st, hi, keep_cols=use_cols)
+        with obs.scope("omp.prefix"):
+            st = _grow_prefix_batched(st, hi, keep_cols=use_cols)
         st = lax.fori_loop(lo, hi, make_body(use_cols), st)
     return st.indices, st.weights, st.mask, st.err
 
@@ -931,16 +948,19 @@ def omp_select_per_class(
             grads, target, k=k_cap, lam=lam, eps=eps, valid=valid,
             method=method,
         )
-        mask = mask & (slot < quota)
-        idx = jnp.where(mask, idx, -1)
         # Exact reweight of the truncated prefix: one NNLS over the
-        # quota-sized active set against the class target.
-        sel = jnp.where(mask, idx, 0)
-        g_s = grads[sel] * mask[:, None].astype(grads.dtype)
-        gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
-        corr = jnp.dot(g_s, target.astype(grads.dtype), precision=PRECISION)
-        w = _nnls_active(gram, corr, mask, lam, nnls_iters)
-        return idx, jnp.where(mask, w, 0.0), mask
+        # quota-sized active set against the class target.  Under the
+        # eager vmap below this runs op by op from the host on every call.
+        with obs.span("omp.reweight"):
+            mask = mask & (slot < quota)
+            idx = jnp.where(mask, idx, -1)
+            sel = jnp.where(mask, idx, 0)
+            g_s = grads[sel] * mask[:, None].astype(grads.dtype)
+            gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
+            corr = jnp.dot(g_s, target.astype(grads.dtype),
+                           precision=PRECISION)
+            w = _nnls_active(gram, corr, mask, lam, nnls_iters)
+            return idx, jnp.where(mask, w, 0.0), mask
 
     idx, w, mask = jax.vmap(one_class)(jnp.arange(num_classes), targets,
                                        quotas_j)

@@ -25,6 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.continual import buffer as continual_lib
 from repro.core import craig as craig_lib
 from repro.core import glister as glister_lib
@@ -121,77 +122,82 @@ def select(
                 f"{strategy!r} — it would be silently ignored")
         if val < 1:
             raise ValueError(f"{name} must be >= 1, got {val}")
-    n = proxies.shape[0]
-    if strategy == "full":
-        w = jnp.full((n,), 1.0 / n, jnp.float32)
-        return SelectionResult(jnp.arange(n, dtype=jnp.int32), w,
-                               jnp.ones((n,), bool), jnp.float32(0.0))
-    if strategy == "random":
-        return random_sel.random_select(key, n, k)
-    if strategy == "gradmatch":
-        if per_class and labels is not None and num_classes > 1 and (
-                val_target is None):
-            return gm_lib.gradmatch_per_class(
-                proxies, labels, num_classes, k, lam=lam, eps=eps,
-                method=omp_method)
-        return gm_lib.gradmatch(proxies, k, target=val_target, lam=lam,
-                                eps=eps, method=omp_method)
-    if strategy == "gradmatch-stream":
-        if stream_cache_bytes <= 0:
-            # The engine itself accepts cache_bytes=0 (certified, but
-            # every commit re-pays a loader pass); through this in-memory
-            # convenience path that trade is never what the caller wants —
-            # it is always a typo or a unit slip (bytes, not MB/rows).
-            raise ValueError(
-                f"stream_cache_bytes must be > 0, got "
-                f"{stream_cache_bytes}: the compressed chunk cache is "
-                "what lets gradmatch-stream commit rounds without "
-                "re-reading the pool.  Pass bytes (e.g. 1 << 24); to "
-                "deliberately run cacheless use "
-                "streaming.omp_select_streaming(cache_bytes=0) directly.")
-        return stream_lib.gradmatch_streaming_array(
-            proxies, k, target=val_target, lam=lam, eps=eps,
-            chunk_size=chunk_size, buffer_size=stream_buffer,
-            cache_bytes=stream_cache_bytes)
-    if strategy == "gradmatch-partitioned":
-        # Partition-and-merge sharded selection (core/partition.py,
-        # DESIGN.md §9): per-class partitions when the per-class mode
-        # applies (mirroring "gradmatch"), hashed partitions otherwise;
-        # out-of-core pools go through
-        # ``partition.gradmatch_partitioned_stream`` directly.
-        use_labels = (per_class and labels is not None and num_classes > 1
-                      and val_target is None)
-        return part_lib.gradmatch_partitioned(
-            proxies, k, partitions=0 if partitions is None else partitions,
-            labels=labels if use_labels else None,
-            num_classes=num_classes if use_labels else 0,
-            target=val_target, lam=lam, eps=eps, method=omp_method)
-    if strategy == "gradmatch-continual":
-        # Bounded-buffer maintained selection (repro.continual, DESIGN.md
-        # §11): the pool is streamed through a fixed-capacity buffer in
-        # admission batches; always pooled (like gradmatch-stream).  With
-        # the default buffer_cap=None the buffer covers the pool and the
-        # result is the pooled gradmatch solution; a smaller cap bounds
-        # memory and selects over the rows surviving eviction.
-        return continual_lib.continual_select(
-            proxies, k, target=val_target, capacity=buffer_cap,
-            batch=continual_batch, lam=lam, eps=eps)
-    if strategy == "gradmatch-pb":
-        return gm_lib.gradmatch_pb(
-            proxies, batch_size, max(k // batch_size, 1), lam=lam, eps=eps,
-            target=val_target, method=omp_method)
-    if strategy in _CRAIG_METHODS:
-        return craig_lib.craig(proxies, k, method=_CRAIG_METHODS[strategy],
-                               key=key,
-                               on_the_fly=(True if strategy in
-                                           _CRAIG_ON_THE_FLY else None))
-    if strategy == "craig-pb":
-        return craig_lib.craig_pb(proxies, batch_size,
-                                  max(k // batch_size, 1))
-    if strategy == "glister":
-        tgt = val_target if val_target is not None else jnp.sum(proxies, 0)
-        return glister_lib.glister(proxies, tgt, k)
-    raise ValueError(f"unknown strategy {strategy!r}; known: {STRATEGIES}")
+    # One span per call, numbered in the process; the spans of the
+    # call's layers nest inside it on this thread.
+    with obs.span("select", strategy=strategy,
+                  call=obs.count("select.calls")):
+        n = proxies.shape[0]
+        if strategy == "full":
+            w = jnp.full((n,), 1.0 / n, jnp.float32)
+            return SelectionResult(jnp.arange(n, dtype=jnp.int32), w,
+                                   jnp.ones((n,), bool), jnp.float32(0.0))
+        if strategy == "random":
+            return random_sel.random_select(key, n, k)
+        if strategy == "gradmatch":
+            if per_class and labels is not None and num_classes > 1 and (
+                    val_target is None):
+                return gm_lib.gradmatch_per_class(
+                    proxies, labels, num_classes, k, lam=lam, eps=eps,
+                    method=omp_method)
+            return gm_lib.gradmatch(proxies, k, target=val_target, lam=lam,
+                                    eps=eps, method=omp_method)
+        if strategy == "gradmatch-stream":
+            if stream_cache_bytes <= 0:
+                # The engine itself accepts cache_bytes=0 (certified, but
+                # every commit re-pays a loader pass); through this
+                # in-memory convenience path that trade is never what the
+                # caller wants — it is always a typo or a unit slip
+                # (bytes, not MB/rows).
+                raise ValueError(
+                    f"stream_cache_bytes must be > 0, got "
+                    f"{stream_cache_bytes}: the compressed chunk cache is "
+                    "what lets gradmatch-stream commit rounds without "
+                    "re-reading the pool.  Pass bytes (e.g. 1 << 24); to "
+                    "deliberately run cacheless use "
+                    "streaming.omp_select_streaming(cache_bytes=0) directly.")
+            return stream_lib.gradmatch_streaming_array(
+                proxies, k, target=val_target, lam=lam, eps=eps,
+                chunk_size=chunk_size, buffer_size=stream_buffer,
+                cache_bytes=stream_cache_bytes)
+        if strategy == "gradmatch-partitioned":
+            # Partition-and-merge sharded selection (core/partition.py,
+            # DESIGN.md §9): per-class partitions when the per-class mode
+            # applies (mirroring "gradmatch"), hashed partitions otherwise;
+            # out-of-core pools go through
+            # ``partition.gradmatch_partitioned_stream`` directly.
+            use_labels = (per_class and labels is not None and num_classes > 1
+                          and val_target is None)
+            return part_lib.gradmatch_partitioned(
+                proxies, k, partitions=0 if partitions is None else partitions,
+                labels=labels if use_labels else None,
+                num_classes=num_classes if use_labels else 0,
+                target=val_target, lam=lam, eps=eps, method=omp_method)
+        if strategy == "gradmatch-continual":
+            # Bounded-buffer maintained selection (repro.continual, DESIGN.md
+            # §11): the pool is streamed through a fixed-capacity buffer in
+            # admission batches; always pooled (like gradmatch-stream).  With
+            # the default buffer_cap=None the buffer covers the pool and the
+            # result is the pooled gradmatch solution; a smaller cap bounds
+            # memory and selects over the rows surviving eviction.
+            return continual_lib.continual_select(
+                proxies, k, target=val_target, capacity=buffer_cap,
+                batch=continual_batch, lam=lam, eps=eps)
+        if strategy == "gradmatch-pb":
+            return gm_lib.gradmatch_pb(
+                proxies, batch_size, max(k // batch_size, 1), lam=lam, eps=eps,
+                target=val_target, method=omp_method)
+        if strategy in _CRAIG_METHODS:
+            return craig_lib.craig(proxies, k, method=_CRAIG_METHODS[strategy],
+                                   key=key,
+                                   on_the_fly=(True if strategy in
+                                               _CRAIG_ON_THE_FLY else None))
+        if strategy == "craig-pb":
+            return craig_lib.craig_pb(proxies, batch_size,
+                                      max(k // batch_size, 1))
+        if strategy == "glister":
+            tgt = val_target if val_target is not None else jnp.sum(proxies, 0)
+            return glister_lib.glister(proxies, tgt, k)
+        raise ValueError(f"unknown strategy {strategy!r}; known: {STRATEGIES}")
 
 
 def expand_if_pb(strategy: str, sel: SelectionResult, batch_size: int,

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import CheckpointManager
 from repro.configs.paper import ClassifierConfig, PaperHParams
 from repro.core import proxies as proxy_lib
@@ -209,8 +210,9 @@ class AdaptiveTrainer:
             in_warm = epoch < warm_epochs
             if (tc.strategy not in ("full",) and not in_warm
                     and sched.is_selection_epoch(epoch)):
-                sel, dt = self._run_selection(
-                    params, jax.random.fold_in(kloop, epoch))
+                with obs.span("train.select", epoch=epoch):
+                    sel, dt = self._run_selection(
+                        params, jax.random.fold_in(kloop, epoch))
                 loader.set_selection(np.asarray(sel.indices),
                                      np.asarray(sel.weights),
                                      np.asarray(sel.mask))

@@ -73,3 +73,25 @@ def test_lm_train_phase_smoke():
     assert rep["arch"] == "xlstm-1.3b"
     assert len(rep["losses"]) == chip_smoke.LM_TRAIN["steps"]
     assert len(rep["rounds"]) == 2
+
+
+def test_run_phase_splits_compile_from_run_time(capsys):
+    """A phase that compiles a new program reports its compile seconds
+    from the program's own counter, in the same fields as before."""
+    import jax
+    import jax.numpy as jnp
+
+    def phase():
+        return jax.jit(lambda v: jnp.cos(v) * 7.0 - 0.5)(jnp.arange(5.0))
+
+    c0 = chip_smoke.compile_seconds()
+    out = chip_smoke.run_phase("tiny", phase)
+    assert chip_smoke.compile_seconds() > c0
+    line = capsys.readouterr().out.strip()
+    assert out.shape == (5,)
+    name, fields = line.split(": ", 1)
+    assert name == "[phase] tiny"
+    got = dict(kv.split("=") for kv in fields.split())
+    assert list(got) == ["wall_s", "compile_s", "run_s"]
+    assert float(got["wall_s"]) == pytest.approx(
+        float(got["compile_s"]) + float(got["run_s"]), abs=0.02)
