@@ -891,6 +891,42 @@ def split_budget(k: int, sizes: Sequence[int]) -> np.ndarray:
     return quota
 
 
+def _class_table(labels, num_classes: int):
+    """Host-side class table: ``(table (C, m) int32, valid (C, m) bool,
+    n_in_range)``.  Row ``c`` lists class ``c``'s global row ids in
+    increasing order (``m`` = largest class size, at least 1); slots past
+    the class's size are padding, invalid and pointing at row 0.  Labels
+    outside ``[0, C)`` are in no class."""
+    lab = np.asarray(labels)
+    rows = np.flatnonzero((lab >= 0) & (lab < num_classes))
+    sizes = np.bincount(lab[rows], minlength=num_classes)
+    m = max(int(sizes.max()), 1)
+    valid = np.arange(m) < sizes[:, None]
+    table = np.zeros((num_classes, m), np.int32)
+    # Row-major boolean assignment fills class 0's slots first, so the
+    # class-stable order lands each class's rows in its own row.
+    table[valid] = rows[np.argsort(lab[rows], kind="stable")]
+    return table, valid, rows.size
+
+
+@functools.partial(jax.jit, static_argnames=("k", "method"))
+def _solve_classes(grads, targets, table, valid, lam, eps, k, method):
+    """``omp_select`` vmapped over the ``(C, m, d)`` class slab
+    ``grads[table]``; returns (global row ids (C, k), -1 on unused slots,
+    weights, mask).  Round ``t`` of a class with ``t`` or fewer rows finds
+    none left and picks local row 0 again: such picks are dropped."""
+
+    def solve(g, target, valid_c):
+        idx, w, mask, _ = omp_select(g, target, k=k, lam=lam, eps=eps,
+                                     valid=valid_c, method=method)
+        mask = mask & (jnp.arange(k) < jnp.sum(valid_c))
+        return idx, jnp.where(mask, w, 0.0), mask
+
+    idx, w, mask = jax.vmap(solve)(grads[table], targets, valid)
+    idx = jnp.take_along_axis(table, jnp.where(mask, idx, 0), axis=1)
+    return jnp.where(mask, idx, -1), w, mask
+
+
 def omp_select_per_class(
     grads: jax.Array,        # (n, d)
     labels: jax.Array,       # (n,) int class ids
@@ -905,8 +941,23 @@ def omp_select_per_class(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Paper's per-class decomposition, batched over classes with vmap.
 
-    Each class-c problem only sees candidates with label c (others masked
-    invalid).  Returns flattened (num_classes*k, ...) padded arrays.
+    Each class-c problem only sees candidates with label c.  Returns
+    flattened (num_classes*k, ...) padded arrays of global row ids, -1 on
+    unused slots.
+
+    The class problems run on the class's own rows: a host-side table
+    (``_class_table``) gathers a ``(C, m, d)`` slab, ``m`` the largest
+    class size, and the solver is vmapped over it, so every round scores
+    ``m`` rows, not ``n``, and each column cache is ``(m, P)``.  A row's
+    score depends only on that row, and each class keeps its rows in
+    increasing global order, so the picks (lowest-index tie-break
+    included), and the weights of the quota reweight, are those of
+    masking the whole pool per class.  The slab is one copy of the pool
+    padded to ``C*m`` rows: on a pool where one class holds almost every
+    row it approaches ``C*n*d``.  Rounds past a class's last row pick
+    nothing; without ``quotas`` the weights of such a class still come
+    from the solve that ran those rounds, so pass ``quotas`` capped at
+    the class sizes (``split_budget``) for exact weights.
 
     ``quotas`` gives each class its own round budget (``split_budget``'s
     output; ``k_per_class`` is ignored then, the vmap runs ``max(quotas)``
@@ -917,53 +968,41 @@ def omp_select_per_class(
     weights are re-solved by one NNLS on the truncated active set (the
     full-budget weights are *not* the prefix weights).
     """
+    if quotas is not None:
+        quotas = np.asarray(quotas, np.int64)
+        if quotas.shape != (num_classes,):
+            raise ValueError(
+                f"quotas must be ({num_classes},), got {quotas.shape}")
+        k_per_class = int(quotas.max()) if quotas.size else 0
+        if k_per_class == 0:            # empty budget: all-off result
+            z = jnp.zeros((0,))
+            return (z.astype(jnp.int32), z.astype(jnp.float32),
+                    z.astype(bool))
+    table, valid, n_in_range = _class_table(labels, num_classes)
+    obs.count("omp.class_rows", table.size)
+    obs.count("omp.class_pad_rows", table.size - n_in_range)
+    idx, w, mask = _solve_classes(grads, targets, table, valid, lam, eps,
+                                  k=k_per_class, method=method)
+    if quotas is not None:
+        slot = jnp.arange(k_per_class, dtype=jnp.int32)
 
-    if quotas is None:
-        def one_class(c, target):
-            valid = labels == c
-            idx, w, mask, _ = omp_select(
-                grads, target, k=k_per_class, lam=lam, eps=eps, valid=valid,
-                method=method,
-            )
-            return idx, w, mask
-
-        idx, w, mask = jax.vmap(one_class)(jnp.arange(num_classes), targets)
-        return idx.reshape(-1), w.reshape(-1), mask.reshape(-1)
-
-    quotas = np.asarray(quotas, np.int64)
-    if quotas.shape != (num_classes,):
-        raise ValueError(
-            f"quotas must be ({num_classes},), got {quotas.shape}")
-    k_cap = int(quotas.max()) if quotas.size else 0
-    if k_cap == 0:                      # empty budget: all-off result
-        z = jnp.zeros((0,))
-        return (z.astype(jnp.int32), z.astype(jnp.float32),
-                z.astype(bool))
-    quotas_j = jnp.asarray(quotas, jnp.int32)
-    slot = jnp.arange(k_cap, dtype=jnp.int32)
-
-    def one_class(c, target, quota):
-        valid = labels == c
-        idx, _, mask, _ = omp_select(
-            grads, target, k=k_cap, lam=lam, eps=eps, valid=valid,
-            method=method,
-        )
-        # Exact reweight of the truncated prefix: one NNLS over the
-        # quota-sized active set against the class target.  Under the
-        # eager vmap below this runs op by op from the host on every call.
-        with obs.span("omp.reweight"):
+        def reweight(target, idx, mask, quota):
+            # Exact reweight of the truncated prefix: one NNLS over the
+            # quota-sized active set against the class target.  Under the
+            # eager vmap below this runs op by op from the host on every
+            # call.
             mask = mask & (slot < quota)
-            idx = jnp.where(mask, idx, -1)
             sel = jnp.where(mask, idx, 0)
             g_s = grads[sel] * mask[:, None].astype(grads.dtype)
             gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
             corr = jnp.dot(g_s, target.astype(grads.dtype),
                            precision=PRECISION)
             w = _nnls_active(gram, corr, mask, lam, nnls_iters)
-            return idx, jnp.where(mask, w, 0.0), mask
+            return jnp.where(mask, idx, -1), jnp.where(mask, w, 0.0), mask
 
-    idx, w, mask = jax.vmap(one_class)(jnp.arange(num_classes), targets,
-                                       quotas_j)
+        with obs.span("omp.reweight"):
+            idx, w, mask = jax.vmap(reweight)(targets, idx, mask,
+                                              jnp.asarray(quotas, jnp.int32))
     return idx.reshape(-1), w.reshape(-1), mask.reshape(-1)
 
 

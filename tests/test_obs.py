@@ -10,10 +10,11 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import omp, selection
+from repro.core import gradmatch, omp, selection
 
 BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "perfbench")
@@ -76,6 +77,21 @@ def test_spans_and_markers_record_nothing_without_a_profiler():
         total = obs.count("test.obs.count", 2)
     assert total == before + 2
     assert obs.counters()["test.obs.count"] == before + 2
+
+
+def test_class_counters_read_the_rows_a_call_scores():
+    # Classes of 5, 20, 0 and 15 rows and 10 rows in no class: the class
+    # slab is 4 x 20 rows, 40 of them padding.
+    sizes = [5, 20, 0, 15]
+    labels = jnp.asarray(np.random.default_rng(0).permutation(
+        np.concatenate([np.repeat(np.arange(4), sizes), [-1] * 5, [4] * 5])))
+    g = jax.random.normal(jax.random.PRNGKey(2), (labels.shape[0], 12))
+    before = obs.counters()
+    jax.block_until_ready(gradmatch.gradmatch_per_class(g, labels, 4, 12))
+    after = obs.counters()
+    rows = after["omp.class_rows"] - before.get("omp.class_rows", 0)
+    pad = after["omp.class_pad_rows"] - before.get("omp.class_pad_rows", 0)
+    assert (rows, pad) == (4 * 20, 4 * 20 - 40)
 
 
 def test_a_new_program_is_loaded_once_and_traced():

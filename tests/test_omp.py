@@ -7,8 +7,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.omp import (matching_error, omp_select, omp_select_dense,
-                            omp_select_per_class)
+from repro.core.omp import (_nnls_active, matching_error, omp_select,
+                            omp_select_dense, omp_select_per_class,
+                            split_budget)
+from repro.kernels import ops
+from repro.kernels.ref import PRECISION
 
 
 def _k(i):
@@ -114,6 +117,106 @@ def test_per_class_selects_within_class():
         block = idx_np[c * 5:(c + 1) * 5]
         bm = mask_np[c * 5:(c + 1) * 5]
         assert (lab_np[block[bm]] == c).all()
+
+
+def _class_pool(seed, sizes, outside=0, d=24):
+    """A shuffled pool with ``sizes[c]`` rows of class c and ``outside``
+    rows labelled -1 or C (in no class); targets by the one-hot
+    contraction ``gradmatch_per_class`` uses."""
+    rng = np.random.default_rng(seed)
+    labels = np.concatenate([np.repeat(np.arange(len(sizes)), sizes),
+                             rng.choice([-1, len(sizes)], outside)])
+    labels = jnp.asarray(rng.permutation(labels))
+    g = jax.random.normal(_k(seed), (labels.shape[0], d))
+    onehot = jax.nn.one_hot(labels, len(sizes), dtype=g.dtype)
+    return g, labels, jnp.dot(onehot.T, g, precision=PRECISION)
+
+
+def _per_class_whole_pool(g, labels, targets, num_classes, k, quotas,
+                          lam=0.5, eps=1e-10, nnls_iters=50):
+    """The whole-pool formulation: each class problem scores all n rows
+    with the other classes masked invalid, then the same quota truncation
+    and exact reweight."""
+    k_run = k if quotas is None else int(max(quotas))
+    slot = jnp.arange(k_run, dtype=jnp.int32)
+
+    def one_class(c, target, quota):
+        valid = labels == c
+        idx, w, mask, _ = omp_select(g, target, k=k_run, lam=lam, eps=eps,
+                                     valid=valid)
+        # Rounds past the class's last row pick a row outside it: dropped.
+        mask = mask & (slot < jnp.sum(valid))
+        if quotas is not None:
+            mask = mask & (slot < quota)
+            sel = jnp.where(mask, idx, 0)
+            g_s = g[sel] * mask[:, None].astype(g.dtype)
+            gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
+            corr = jnp.dot(g_s, target, precision=PRECISION)
+            w = _nnls_active(gram, corr, mask, lam, nnls_iters)
+        return jnp.where(mask, idx, -1), jnp.where(mask, w, 0.0), mask
+
+    q = jnp.asarray(np.zeros(num_classes) if quotas is None else quotas,
+                    jnp.int32)
+    idx, w, mask = jax.vmap(one_class)(jnp.arange(num_classes), targets, q)
+    return idx.reshape(-1), w.reshape(-1), mask.reshape(-1)
+
+
+# (class sizes, rows in no class, budget, ops backend, proxy width d); the
+# budget is a global k split by split_budget, an explicit quota list, or
+# ("k_per_class", k) for the quotas=None branch.
+PER_CLASS_PARITY = {
+    "shuffled": ([40, 40, 40], 0, 30, "ref", 24),
+    "unequal_sizes": ([4, 30, 50, 16], 0, 40, "ref", 24),
+    "empty_class": ([30, 0, 45], 0, 24, "ref", 24),
+    "labels_outside": ([30, 25, 35], 20, 27, "ref", 24),
+    "quota_over_class": ([3, 20, 20], 0, [6, 5, 5], "ref", 24),
+    "no_quotas": ([40, 40, 40], 0, ("k_per_class", 8), "ref", 24),
+    "no_quotas_empty_outside": ([30, 0, 25, 35], 10, ("k_per_class", 6),
+                                "ref", 24),
+    "pallas_interpret": ([12, 20, 9], 5, 15, "interpret", 40),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PER_CLASS_PARITY))
+def test_per_class_matches_whole_pool_formulation(case):
+    """Solving each class on its own rows picks and weighs exactly what
+    solving it on the whole pool with the other classes masked does."""
+    sizes, outside, budget, backend, d = PER_CLASS_PARITY[case]
+    g, labels, targets = _class_pool(sum(sizes) + outside, sizes, outside,
+                                     d)
+    C = len(sizes)
+    k, quotas = 0, None
+    if isinstance(budget, tuple):
+        k = budget[1]
+    elif isinstance(budget, list):
+        quotas = budget
+    else:
+        quotas = split_budget(budget, sizes).tolist()
+    ops.set_backend(backend)
+    try:
+        got = omp_select_per_class(g, labels, targets, C, k, quotas=quotas)
+        want = _per_class_whole_pool(g, labels, targets, C, k, quotas)
+    finally:
+        ops.set_backend(None)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+    if quotas is not None:
+        # The reweight solves from the picked rows alone: bit for bit.
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(want[1]))
+    else:
+        # The solver's own weights come from its cached correlations,
+        # which a per-class matrix-vector product rounds in another order
+        # than one pool-wide matrix product: f32 rounding apart.
+        np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]),
+                                   rtol=1e-5, atol=1e-6)
+    idx, mask = np.asarray(got[0]), np.asarray(got[2])
+    assert mask.any()
+    lab = np.asarray(labels)
+    per_class = idx.reshape(C, -1)
+    for c in range(C):
+        picked = per_class[c][per_class[c] >= 0]
+        assert (lab[picked] == c).all()
 
 
 # ---------------------------------------------------------------------------
