@@ -15,6 +15,7 @@ unweighted mean and the usual LR schedules transfer.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -102,11 +103,21 @@ def gradmatch_per_class(
                                 dtype=grads.dtype)                   # (n, C)
         targets = jnp.dot(onehot.T, grads, precision=PRECISION)      # (C, d)
     with obs.span("omp.per_class"):
-        idx, w, mask = omp_lib.omp_select_per_class(
-            grads, labels, targets, num_classes, 0, lam=lam, eps=eps,
-            method=method, quotas=quotas,
-        )
-    with obs.span("gradmatch.err"):
+        table, valid = omp_lib._class_table(labels_np, num_classes)
+        return _per_class(grads, targets, table, valid,
+                          quotas.astype(np.int32), lam, eps,
+                          k=int(quotas.max()), method=method)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "method"))
+def _per_class(grads, targets, table, valid, quotas, lam, eps, k, method):
+    """``gradmatch_per_class``'s one program: the per-class solve and
+    reweight, the objective against the summed target and the global
+    normalisation."""
+    idx, w, mask = omp_lib._solve_classes(grads, targets, table, valid,
+                                          quotas, lam, eps, k=k,
+                                          method=method)
+    with obs.scope("gradmatch.err"):
         err = omp_lib.matching_error(grads, jnp.sum(targets, axis=0), idx,
                                      w, mask, lam=lam)
     # Per-class weights each sum to ~their class share; renormalize globally.

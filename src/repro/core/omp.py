@@ -892,11 +892,11 @@ def split_budget(k: int, sizes: Sequence[int]) -> np.ndarray:
 
 
 def _class_table(labels, num_classes: int):
-    """Host-side class table: ``(table (C, m) int32, valid (C, m) bool,
-    n_in_range)``.  Row ``c`` lists class ``c``'s global row ids in
-    increasing order (``m`` = largest class size, at least 1); slots past
-    the class's size are padding, invalid and pointing at row 0.  Labels
-    outside ``[0, C)`` are in no class."""
+    """Host-side class table: ``(table (C, m) int32, valid (C, m) bool)``.
+    Row ``c`` lists class ``c``'s global row ids in increasing order
+    (``m`` = largest class size, at least 1); slots past the class's size
+    are padding, invalid and pointing at row 0.  Labels outside ``[0, C)``
+    are in no class.  Counts the slab's rows and padding."""
     lab = np.asarray(labels)
     rows = np.flatnonzero((lab >= 0) & (lab < num_classes))
     sizes = np.bincount(lab[rows], minlength=num_classes)
@@ -906,15 +906,35 @@ def _class_table(labels, num_classes: int):
     # Row-major boolean assignment fills class 0's slots first, so the
     # class-stable order lands each class's rows in its own row.
     table[valid] = rows[np.argsort(lab[rows], kind="stable")]
-    return table, valid, rows.size
+    obs.count("omp.class_rows", table.size)
+    obs.count("omp.class_pad_rows", table.size - rows.size)
+    return table, valid
 
 
-@functools.partial(jax.jit, static_argnames=("k", "method"))
-def _solve_classes(grads, targets, table, valid, lam, eps, k, method):
-    """``omp_select`` vmapped over the ``(C, m, d)`` class slab
-    ``grads[table]``; returns (global row ids (C, k), -1 on unused slots,
-    weights, mask).  Round ``t`` of a class with ``t`` or fewer rows finds
-    none left and picks local row 0 again: such picks are dropped."""
+def _reweight(grads, target, idx, mask, quota, lam, nnls_iters):
+    """Exact weights of a class's first ``quota`` picks: one NNLS over the
+    truncated active set against the class target."""
+    mask = mask & (jnp.arange(mask.shape[0]) < quota)
+    sel = jnp.where(mask, idx, 0)
+    g_s = grads[sel] * mask[:, None].astype(grads.dtype)
+    gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
+    corr = jnp.dot(g_s, target.astype(grads.dtype), precision=PRECISION)
+    w = _nnls_active(gram, corr, mask, lam, nnls_iters)
+    return jnp.where(mask, idx, -1), jnp.where(mask, w, 0.0), mask
+
+
+@functools.partial(jax.jit, static_argnames=("k", "method", "nnls_iters"))
+def _solve_classes(grads, targets, table, valid, quotas, lam, eps, k, method,
+                   nnls_iters=50):
+    """The per-class program: ``omp_select`` vmapped over the ``(C, m, d)``
+    class slab ``grads[table]`` for ``k`` rounds, then, with ``quotas``
+    (``(C,)`` int32, traced), each class's truncation to its quota and
+    reweight.  Returns flat (global row ids, -1 on unused slots, weights,
+    mask).  Round ``t`` of a class with ``t`` or fewer rows finds none
+    left and picks local row 0 again: such picks are dropped."""
+    if k == 0:                          # empty budget: all-off result
+        return (jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.float32),
+                jnp.zeros((0,), bool))
 
     def solve(g, target, valid_c):
         idx, w, mask, _ = omp_select(g, target, k=k, lam=lam, eps=eps,
@@ -924,7 +944,14 @@ def _solve_classes(grads, targets, table, valid, lam, eps, k, method):
 
     idx, w, mask = jax.vmap(solve)(grads[table], targets, valid)
     idx = jnp.take_along_axis(table, jnp.where(mask, idx, 0), axis=1)
-    return jnp.where(mask, idx, -1), w, mask
+    idx = jnp.where(mask, idx, -1)
+    if quotas is not None:
+        with obs.scope("omp.reweight"):
+            idx, w, mask = jax.vmap(
+                functools.partial(_reweight, grads, lam=lam,
+                                  nnls_iters=nnls_iters))(
+                targets, idx, mask, quotas)
+    return idx.reshape(-1), w.reshape(-1), mask.reshape(-1)
 
 
 def omp_select_per_class(
@@ -967,6 +994,11 @@ def omp_select_per_class(
     truncated prefix equals a fresh ``quotas[c]``-round solve — and its
     weights are re-solved by one NNLS on the truncated active set (the
     full-budget weights are *not* the prefix weights).
+
+    Solve, truncation and reweight are one compiled program
+    (``_solve_classes``), keyed on ``n``, ``d``, ``C``, ``m`` and the
+    round budget; the quotas are a traced argument, so other class sizes
+    at the same shapes run the same program.
     """
     if quotas is not None:
         quotas = np.asarray(quotas, np.int64)
@@ -974,36 +1006,11 @@ def omp_select_per_class(
             raise ValueError(
                 f"quotas must be ({num_classes},), got {quotas.shape}")
         k_per_class = int(quotas.max()) if quotas.size else 0
-        if k_per_class == 0:            # empty budget: all-off result
-            z = jnp.zeros((0,))
-            return (z.astype(jnp.int32), z.astype(jnp.float32),
-                    z.astype(bool))
-    table, valid, n_in_range = _class_table(labels, num_classes)
-    obs.count("omp.class_rows", table.size)
-    obs.count("omp.class_pad_rows", table.size - n_in_range)
-    idx, w, mask = _solve_classes(grads, targets, table, valid, lam, eps,
-                                  k=k_per_class, method=method)
-    if quotas is not None:
-        slot = jnp.arange(k_per_class, dtype=jnp.int32)
-
-        def reweight(target, idx, mask, quota):
-            # Exact reweight of the truncated prefix: one NNLS over the
-            # quota-sized active set against the class target.  Under the
-            # eager vmap below this runs op by op from the host on every
-            # call.
-            mask = mask & (slot < quota)
-            sel = jnp.where(mask, idx, 0)
-            g_s = grads[sel] * mask[:, None].astype(grads.dtype)
-            gram = jnp.dot(g_s, g_s.T, precision=PRECISION)
-            corr = jnp.dot(g_s, target.astype(grads.dtype),
-                           precision=PRECISION)
-            w = _nnls_active(gram, corr, mask, lam, nnls_iters)
-            return jnp.where(mask, idx, -1), jnp.where(mask, w, 0.0), mask
-
-        with obs.span("omp.reweight"):
-            idx, w, mask = jax.vmap(reweight)(targets, idx, mask,
-                                              jnp.asarray(quotas, jnp.int32))
-    return idx.reshape(-1), w.reshape(-1), mask.reshape(-1)
+        quotas = quotas.astype(np.int32)
+    table, valid = _class_table(labels, num_classes)
+    return _solve_classes(grads, targets, table, valid, quotas, lam, eps,
+                          k=k_per_class, method=method,
+                          nnls_iters=nnls_iters)
 
 
 def matching_error(

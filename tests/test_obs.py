@@ -61,7 +61,6 @@ def test_a_select_span_lands_in_a_cpu_trace(tmp_path):
     names = [n for _, _, n in host]
     for span in ("repro.select", "repro.gradmatch.budget",
                  "repro.gradmatch.targets", "repro.omp.per_class",
-                 "repro.omp.reweight", "repro.gradmatch.err",
                  "repro.count.select.calls"):
         assert span in names, span
     # Every span of the call nests inside the call's own span.
@@ -69,6 +68,20 @@ def test_a_select_span_lands_in_a_cpu_trace(tmp_path):
     for s, d, n in host:
         if n.startswith("repro.") and n != "repro.count.select.calls":
             assert s0 <= s and s + d <= s0 + d0, n
+    # The reweight and the objective run inside the call's one program:
+    # device scopes, not host spans.
+    for span in ("repro.omp.reweight", "repro.gradmatch.err"):
+        assert span not in names, span
+    table, valid = omp._class_table(labels, 3)
+    targets = jax.nn.one_hot(labels, 3).T @ g
+    locs = _locations(gradmatch._per_class.lower(
+        g, targets, table, valid, np.full(3, 4, np.int32), 0.5, 1e-10, k=4,
+        method="incremental"))
+    for scope, op in (("omp.reweight", "while"), ("gradmatch.err", "sub")):
+        scoped = [loc for loc in locs if scope + "/" in loc]
+        assert any(op in loc.split(scope + "/", 1)[1].split("/")
+                   for loc in scoped), scope
+        assert {program_trace.scope_of(loc) for loc in scoped} == {scope}
 
 
 def test_spans_and_markers_record_nothing_without_a_profiler():
@@ -92,6 +105,26 @@ def test_class_counters_read_the_rows_a_call_scores():
     rows = after["omp.class_rows"] - before.get("omp.class_rows", 0)
     pad = after["omp.class_pad_rows"] - before.get("omp.class_pad_rows", 0)
     assert (rows, pad) == (4 * 20, 4 * 20 - 40)
+
+
+def test_a_repeated_per_class_call_traces_and_loads_nothing():
+    """The per-class path is one compiled program keyed on the shapes; the
+    quotas are traced, so other class sizes at the same slab width and
+    round budget reuse it."""
+    g, labels = _pool()                      # classes of 40, 40, 40
+    # Classes of 40, 30 and 40 and 10 rows in none: the slab is still 40
+    # wide, and k 11 splits 4, 3, 4 against the first pool's 4, 4, 4.
+    relabelled = jnp.asarray(np.random.default_rng(1).permutation(
+        np.repeat([0, 1, 2, -1], [40, 30, 40, 10])))
+    jax.block_until_ready(gradmatch.gradmatch_per_class(g, labels, 3, 12))
+    for lab, k in ((labels, 12), (relabelled, 11)):
+        before = obs.counters()
+        res = jax.block_until_ready(
+            gradmatch.gradmatch_per_class(g, lab, 3, k))
+        after = obs.counters()
+        assert int(res.mask.sum()) == k
+        for key in ("jax.traces", "jax.program_loads"):
+            assert after.get(key, 0) - before.get(key, 0) == 0, (key, k)
 
 
 def test_a_new_program_is_loaded_once_and_traced():
